@@ -1,7 +1,7 @@
-"""Micro-benchmarks of the compute hot-spot layers: Pallas-kernel oracles vs
-the naive jnp formulations (wall-clock here is CPU interpret-mode — the
-meaningful derived number is the ALGORITHMIC byte/flop ratio; real-TPU timing
-is out of scope for this container)."""
+"""Micro-benchmarks of the compute hot-spot layers: the jnp formulations the
+Pallas kernels replace, timed on the default backend, with the ALGORITHMIC
+byte/step ratios the kernels buy as the derived numbers.  No Pallas kernel
+is timed here yet."""
 
 from __future__ import annotations
 

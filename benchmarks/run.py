@@ -1,5 +1,6 @@
 """Benchmark harness — one entry per paper table/figure plus the roofline
-aggregation.  Prints ``name,us_per_call,derived`` CSV."""
+aggregation.  Prints ``name,us_per_call,derived`` CSV and exits non-zero
+when any entry failed (its row then carries ``ERROR:<reason>``)."""
 
 from __future__ import annotations
 
@@ -24,7 +25,10 @@ from benchmarks import (  # noqa: E402
 )
 
 
-def main() -> None:
+def main() -> int:
+    from repro.core.cache import setup_compilation_cache
+
+    setup_compilation_cache()
     os.makedirs("results", exist_ok=True)
     rows = []
     benches = [
@@ -48,17 +52,22 @@ def main() -> None:
         ("kernels", kernels_bench.run),
         ("roofline", lambda: [roofline_table.run()]),
     ]
+    failed = []
     for name, fn in benches:
         try:
             rows.extend(fn())
-        except Exception as e:  # keep the harness robust; report the failure
+        except Exception as e:  # run the rest, report the failure, exit 1
             traceback.print_exc()
+            failed.append(name)
             rows.append({"name": name, "us_per_call": -1.0, "derived": f"ERROR:{e}"})
 
     print("name,us_per_call,derived")
     for r in rows:
         print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
+    if failed:
+        print(f"FAILED: {','.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -183,16 +183,17 @@ def async_engine_vs_host(iters: int, replicas: int, seed: int = 0) -> dict:
     }
 
 
-def cold_probe(smoke: bool, specialize: bool, cache_dir: str) -> None:
+def cold_probe(smoke: bool, specialize: bool) -> None:
     """``--cold-probe`` entry: ONE cold sweep dispatch of the bench grid in
     THIS (expected fresh) process, with the persistent compilation cache
-    rooted at ``cache_dir``.  Prints a one-line JSON record — wall seconds
-    plus the cache-entry delta (the observable XLA compile count: 0 means
-    every executable loaded from disk) — and exits.  ``run()`` spawns this
-    twice against one directory to measure uncached-vs-cached cold start."""
-    from repro.core.cache import cache_entries, enable_persistent_cache
+    where ``JAX_COMPILATION_CACHE_DIR`` puts it.  Prints a one-line JSON
+    record — wall seconds plus the cache-entry delta (the observable XLA
+    compile count: 0 means every executable loaded from disk) — and exits.
+    ``run()`` spawns this twice against one directory to measure
+    uncached-vs-cached cold start."""
+    from repro.core.cache import cache_entries, setup_compilation_cache
 
-    enable_persistent_cache(cache_dir)
+    cache_dir = setup_compilation_cache()
     entries_before = cache_entries(cache_dir)
     iters = 200 if smoke else ITERS
     replicas = 8 if smoke else REPLICAS
@@ -219,8 +220,7 @@ def _run_cold_probe(smoke: bool, specialize: bool, cache_dir: str) -> dict:
     """Spawn ``--cold-probe`` as a FRESH python process (a true cold start:
     no in-memory program cache, no jit cache, only the disk cache survives)
     and parse its JSON line."""
-    cmd = [sys.executable, os.path.abspath(__file__),
-           "--cold-probe", "--cache-dir", cache_dir]
+    cmd = [sys.executable, os.path.abspath(__file__), "--cold-probe"]
     if smoke:
         cmd.append("--smoke")
     if not specialize:
@@ -229,6 +229,7 @@ def _run_cold_probe(smoke: bool, specialize: bool, cache_dir: str) -> dict:
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                        "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     out = subprocess.run(cmd, capture_output=True, text=True, env=env, check=True)
     return json.loads(out.stdout.strip().splitlines()[-1])
 
@@ -240,7 +241,17 @@ def measure_cold_cache(smoke: bool, specialize: bool, cache_dir: str | None) -> 
     the directory may arrive pre-warmed — then the first probe already hits
     (``uncached_added_entries == 0``) and the uncached-vs-cached ratio is
     meaningless; ``check_bench.py`` skips the ratio gate in that case but
-    still enforces ``cached_added_entries == 0``."""
+    still enforces ``cached_added_entries == 0``.
+
+    The probes are child processes that need the device, so this refuses to
+    run from a parent that holds an accelerator (the parent has touched jax
+    and keeps the chip): pass ``--skip-cold-probe`` there."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "the cold-cache probe starts child processes that need the "
+            f"device, but this process holds the {jax.default_backend()} "
+            "backend; rerun with --skip-cold-probe"
+        )
     if cache_dir is None:
         tmp = tempfile.TemporaryDirectory(prefix="repro-xla-cache-")
         cache_dir, ctx = tmp.name, tmp
@@ -428,14 +439,15 @@ def main():
                     help="omit the cold_cache section (no subprocesses)")
     ap.add_argument("--cold-probe", action="store_true",
                     help="internal: run ONE cold dispatch in this process "
-                         "against --cache-dir and print its JSON line")
+                         "against $JAX_COMPILATION_CACHE_DIR and print its "
+                         "JSON line")
     args = ap.parse_args()
     if args.cold_probe:
-        if not args.cache_dir:
-            raise SystemExit("--cold-probe requires --cache-dir")
-        cold_probe(smoke=args.smoke, specialize=not args.no_specialize,
-                   cache_dir=args.cache_dir)
+        cold_probe(smoke=args.smoke, specialize=not args.no_specialize)
         return
+    from repro.core.cache import setup_compilation_cache
+
+    setup_compilation_cache()
     print(json.dumps(
         run(args.out, smoke=args.smoke, specialize=not args.no_specialize,
             cache_dir=args.cache_dir, skip_cold_probe=args.skip_cold_probe),

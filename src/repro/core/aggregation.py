@@ -45,6 +45,7 @@ __all__ = [
     "stale_weighted_loss",
     "fastest_k_mask_time",
     "fastest_k_draw",
+    "ordered_sum",
     "active_worker_mean_loss",
     "AGG_KINDS",
     "AGG_MEAN",
@@ -168,13 +169,13 @@ def per_example_weights(
     of the leading batch axis.
     """
     s = examples_per_worker
-    w_worker = mask / (k.astype(mask.dtype) * s)
+    w_worker = mask * (1.0 / (k.astype(mask.dtype) * s))
     return jnp.repeat(w_worker, s, total_repeat_length=mask.shape[0] * s)
 
 
 def masked_mean_weights(mask: jax.Array, k: jax.Array) -> jax.Array:
     """Per-worker weights m_i / k (for losses already averaged within a worker)."""
-    return mask / k.astype(mask.dtype)
+    return mask * (1.0 / k.astype(mask.dtype))
 
 
 def fastest_k_weighted_loss(
@@ -192,7 +193,7 @@ def fastest_k_weighted_loss(
     """
     s = examples_per_worker
     shard_sums = per_example_losses.reshape(-1, s).sum(axis=1)  # (n,)
-    return jnp.dot(shard_sums, mask) / (k.astype(per_example_losses.dtype) * s)
+    return jnp.dot(shard_sums, mask) * (1.0 / (k.astype(per_example_losses.dtype) * s))
 
 
 def stale_weighted_loss(
@@ -248,8 +249,29 @@ def fastest_k_draw(
     return mask, t
 
 
+def ordered_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis in a fixed pairwise order (zero-padded to 2^j).
+
+    ``jnp.sum`` leaves the order of its adds to the backend, and XLA:TPU
+    picks it from the array's physical layout, which it chooses per program
+    from the shapes: the same (lanes, 400) eval reduction runs along the
+    128-wide lane axis in a 32-lane program and along the sublanes in a
+    480-lane one, and the two round differently.  Halving adds are
+    elementwise, so this sum's bits do not depend on layout, lane count or
+    mesh shape — the bitwise sweep-vs-looped contract on the chip.
+    """
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    if width > n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def active_worker_mean_loss(
-    per_example_losses: jax.Array, n_active: jax.Array, n_slots: int,
+    per_example_losses: jax.Array, n_active, n_slots: int,
     examples_per_worker: int,
 ) -> jax.Array:
     """Mean loss over the ACTIVE workers' examples (the first n_active shards).
@@ -258,9 +280,10 @@ def active_worker_mean_loss(
     only the first ``n_active`` own data that trains; their shards are the
     cell's objective.  ``n_active`` may be traced (it is a grid leaf in the
     sweep engine), so both forms are computed and selected: when every slot
-    is active the result is **bitwise-equal** to ``jnp.mean(losses)`` — the
-    pre-heterogeneity engines' eval — because ``jnp.where`` passes the
-    selected operand through unchanged.
+    is active the full mean's scale ``1/m`` is a host constant, the same
+    bits whether ``n_active`` is traced (sweep) or a Python int (the looped
+    engine's homogeneous eval, where the select folds away).  Both sums are
+    ``ordered_sum``s of the per-shard ``ordered_sum``s.
 
     ``n_active == 0`` (an all-crashed fleet has no objective left) is
     pinned to **+inf**, not the 0/0 NaN the naive division would produce:
@@ -269,11 +292,12 @@ def active_worker_mean_loss(
     keep their bits) — and the zero-count lane is overridden by a select.
     """
     s = examples_per_worker
-    full = jnp.mean(per_example_losses)
-    shard_sums = per_example_losses.reshape(n_slots, s).sum(axis=1)
-    active = (jnp.arange(n_slots) < n_active).astype(per_example_losses.dtype)
-    masked = jnp.dot(shard_sums, active) / (
-        jnp.maximum(n_active, 1).astype(per_example_losses.dtype) * s
+    dtype = per_example_losses.dtype
+    shard_sums = ordered_sum(per_example_losses.reshape(n_slots, s))
+    full = ordered_sum(shard_sums) * (1.0 / (n_slots * s))
+    active = (jnp.arange(n_slots) < n_active).astype(dtype)
+    masked = ordered_sum(shard_sums * active) * (
+        1.0 / (jnp.maximum(n_active, 1).astype(dtype) * s)
     )
     masked = jnp.where(n_active == 0, jnp.inf, masked)
     return jnp.where(n_active == n_slots, full, masked)
@@ -345,7 +369,7 @@ def trimmed_mean_rows(
     pos = jnp.arange(n, dtype=jnp.int32)[:, None]
     keep = (pos >= t) & (pos <= k - 1 - t)
     cnt = (k - 2 * t).astype(mat.dtype)
-    return jnp.sum(jnp.where(keep, svals, 0.0), axis=0) / cnt
+    return jnp.sum(jnp.where(keep, svals, 0.0), axis=0) * (1.0 / cnt)
 
 
 def coordinate_median_rows(
@@ -374,7 +398,7 @@ def geometric_median_rows(
     a 0/0 when y lands exactly on a data point.
     """
     kf = k.astype(mat.dtype)
-    y = jnp.tensordot(mask, mat, axes=1) / kf
+    y = jnp.tensordot(mask, mat, axes=1) * (1.0 / kf)
     for _ in range(n_iter):
         d = jnp.sqrt(jnp.sum((mat - y[None, :]) ** 2, axis=1))
         w = mask / jnp.maximum(d, _WEISZFELD_EPS)

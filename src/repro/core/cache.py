@@ -2,11 +2,15 @@
 
 The in-memory program caches (``montecarlo._PROGRAM_CACHE`` and the sweep
 engine's twin, keyed on ``GridSignature`` + ``source.cache_token()`` + static
-shapes) die with the process — a production cold start re-traces AND re-runs
-XLA for every program, which on the committed baseline grid is half the cold
-dispatch (BENCH_sweep.json: 14.4s cold vs 7.2s warm).  This module wires
-jax's persistent compilation cache behind an explicit opt-in so a fresh
-process loads compiled executables from disk instead.
+shapes) die with the process — a cold start re-traces AND re-runs XLA for
+every program.  ``setup_compilation_cache`` turns on jax's persistent
+compilation cache so a fresh process loads compiled executables from disk.
+
+Where the cache lives is decided outside the program: if
+``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and that is the
+directory (nothing here sets another); otherwise it is the fixed
+``<checkout>/.jax_cache`` (listed in ``.gitignore``).  The path is part of
+jax's cache key, so a fixed path is what lets a later process hit.
 
 Key convention — how disk entries line up with the in-memory keys: jax keys
 the disk cache on a fingerprint of the *traced program* (HLO + compile
@@ -25,18 +29,9 @@ compile (the dominant cost) that the disk cache removes.  Entries are
 backend- and jax-version-scoped by jax's fingerprint, so one directory is
 safe to share across heterogeneous hosts; stale entries are simply never hit.
 
-Opt-in, never default: tests and benchmarks measure *uncached* compile unless
-they explicitly warm a directory, so enabling globally would corrupt the
-committed cold-start baselines.  ``benchmarks/sweep_bench.py --cold-probe``
-and tests/test_podscale.py drive this via fresh subprocesses.
-
-Usage::
-
-    from repro.core import cache
-    cache.enable_persistent_cache("/var/cache/repro-xla")   # or
-    cache.maybe_enable_from_env()   # REPRO_COMPILATION_CACHE_DIR
-
-    # CLI: python -m repro.launch.train --cache-dir /var/cache/repro-xla
+The entry points (``launch/train.py``, ``chip_smoke.py``, the benchmark
+CLIs) call ``setup_compilation_cache()`` before their first compile; nothing
+calls it at import, so library users and the tests keep jax's defaults.
 """
 
 from __future__ import annotations
@@ -46,50 +41,36 @@ from typing import Optional
 
 import jax
 
-__all__ = [
-    "enable_persistent_cache",
-    "disable_persistent_cache",
-    "persistent_cache_dir",
-    "cache_entries",
-    "maybe_enable_from_env",
-    "ENV_VAR",
-]
+__all__ = ["ENV_VAR", "DEFAULT_DIR", "setup_compilation_cache", "cache_entries"]
 
-# Environment opt-in consumed by maybe_enable_from_env() (train.py calls it,
-# and subprocess tests use it to enable caching without code changes).
-ENV_VAR = "REPRO_COMPILATION_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))),
+    ".jax_cache",
+)
 
 
-def enable_persistent_cache(cache_dir: str) -> str:
-    """Enable jax's on-disk compilation cache rooted at ``cache_dir``.
+def setup_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
 
-    Creates the directory if needed and removes jax's default size/time
-    floors (min entry size, min compile seconds) so EVERY executable
-    persists — the sweep grids this repo compiles are seconds-scale
-    programs, but the floors would silently skip the small auxiliary
-    executables (eval reshapes, summaries) and leave a fresh process still
-    paying a compile.  Also enables the XLA-level sub-caches (autotune
-    results etc.) where the backend supports them.
-
-    Idempotent; returns the (absolute) cache directory.
+    Uses ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``DEFAULT_DIR``.
+    Removes jax's default size/time floors (min entry size, min compile
+    seconds) so EVERY executable persists — the sweep grids this repo
+    compiles are seconds-scale programs, but the floors would silently skip
+    the small auxiliary executables and leave a fresh process still paying
+    a compile.  Also enables the XLA-level sub-caches (autotune results
+    etc.) where the backend supports them.  Idempotent; must run before the
+    process's first compile to take effect.
     """
-    cache_dir = os.path.abspath(cache_dir)
+    cache_dir = os.environ.get(ENV_VAR) or DEFAULT_DIR
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
     return cache_dir
-
-
-def disable_persistent_cache() -> None:
-    """Turn the on-disk cache back off (in-memory caches are untouched)."""
-    jax.config.update("jax_compilation_cache_dir", None)
-
-
-def persistent_cache_dir() -> Optional[str]:
-    """The active cache directory, or None when disk caching is off."""
-    return jax.config.jax_compilation_cache_dir
 
 
 def cache_entries(cache_dir: Optional[str] = None) -> int:
@@ -98,20 +79,10 @@ def cache_entries(cache_dir: Optional[str] = None) -> int:
     compile count: a fully-warmed process adds exactly 0, a changed
     ``GridSignature`` adds exactly the newly-compiled executables."""
     if cache_dir is None:
-        cache_dir = persistent_cache_dir()
+        cache_dir = jax.config.jax_compilation_cache_dir
     if cache_dir is None or not os.path.isdir(cache_dir):
         return 0
     n = 0
     for _, _, files in os.walk(cache_dir):
         n += len(files)
     return n
-
-
-def maybe_enable_from_env() -> Optional[str]:
-    """Enable the cache iff ``REPRO_COMPILATION_CACHE_DIR`` is set (and
-    non-empty); returns the directory or None.  The launcher calls this so
-    deployments opt in via environment without touching code."""
-    cache_dir = os.environ.get(ENV_VAR, "")
-    if not cache_dir:
-        return None
-    return enable_persistent_cache(cache_dir)

@@ -67,13 +67,14 @@ __all__ = [
     "ExecCarry",
     "ModePrelude",
     "zero_stats",
+    "sgd_update",
     "init_exec_carry",
     "make_stale_grad_fns",
     "make_mode_prelude_and_tails",
     "make_mode_steps",
 ]
 
-# Branch order is load-bearing: repro.core.sweep builds its lax.switch over
+# Branch order is load-bearing: repro.core.sweep builds its select over
 # modes in this index order and bakes the indices into compiled programs.
 MODES = {"sync": 0, "kasync": 1, "kbatch": 2}
 MODE_SYNC, MODE_KASYNC, MODE_KBATCH = MODES["sync"], MODES["kasync"], MODES["kbatch"]
@@ -148,6 +149,21 @@ def init_exec_carry(
     )
 
 
+def sgd_update(params, g, eta):
+    """The sim engines' plain SGD step ``p - eta * g``, on a materialized
+    gradient.
+
+    The barrier stops XLA from reassociating ``eta`` into the gradient's own
+    trailing scalar factors (``1/k``, a trimmed mean's ``1/count``): it
+    folds such chains only when the factors are compile-time constants — a
+    looped fixed-k cell — never when they are a sweep cell's traced leaves,
+    and the two orders round differently.  With the barrier both engines
+    apply the same multiply to the same gradient bits.
+    """
+    g = jax.lax.optimization_barrier(g)
+    return jax.tree.map(lambda pa, gi: pa - eta * gi, params, g)
+
+
 def _slot_bcast(mask: jax.Array, like: jax.Array) -> jax.Array:
     """(n_slots,) mask reshaped to broadcast against an (n_slots, ...) leaf."""
     return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
@@ -203,7 +219,7 @@ class ModePrelude(NamedTuple):
 
     Every field is computed identically by each mode that consumes it (the
     sync/kasync pair consumes all of them; kbatch only ``new_key``/``sub``/
-    ``k``), so in a mixed-mode grid the per-cell ``lax.switch`` selects only
+    ``k``), so in a mixed-mode grid the per-cell select picks only
     the cheap mode *bookkeeping* tails — per-slot sampling, ranking, and the
     order statistic are traced once per event instead of once per branch.
     For a sync-mode cell ``pending`` is identically False, so ``remaining``
@@ -240,7 +256,7 @@ def make_mode_prelude_and_tails(
     ``prelude(carry)`` performs the mode-invariant work (key split, fresh
     per-slot draw, renewal residuals, fastest-K ranking/order statistic,
     comm); ``tails[mode](carry, prelude)`` each return ``(new_carry, k)``
-    with identical pytree structure, so a per-cell ``lax.switch`` over the
+    with identical pytree structure, so a per-cell select over the
     tails vmaps cleanly.  ``tails[mode](carry, prelude(carry))`` is exactly
     the historical full step for that mode, op for op — callers that trace a
     single mode (``make_mode_steps``) and callers that switch over tails
@@ -295,10 +311,7 @@ def make_mode_prelude_and_tails(
     if apply_update is None:
 
         def apply_update(params, g, opt_state):
-            return (
-                jax.tree.map(lambda pa, gi: pa - eta * gi, params, g),
-                opt_state,
-            )
+            return sgd_update(params, g, eta), opt_state
 
     has_crash = faults is not None and faults.time is not None
     has_grad_fault = faults is not None and faults.weight is not None
@@ -328,7 +341,7 @@ def make_mode_prelude_and_tails(
             g = jax.tree.map(
                 lambda gl, zl: jnp.where(
                     faults.any_gauss,
-                    gl + jnp.tensordot(arrive_f, zl, axes=1) / kf,
+                    gl + jnp.tensordot(arrive_f, zl, axes=1) * (1.0 / kf),
                     gl,
                 ),
                 g,
@@ -421,7 +434,9 @@ def make_mode_prelude_and_tails(
         kf = k.astype(jnp.float32)
         stats = ExecStats(
             arrivals=jnp.asarray(k, jnp.int32),
-            mean_staleness=jnp.dot(arrive_f, carry.staleness.astype(jnp.float32)) / kf,
+            mean_staleness=(
+                jnp.dot(arrive_f, carry.staleness.astype(jnp.float32)) * (1.0 / kf)
+            ),
             max_staleness=jnp.max(jnp.where(arrive, carry.staleness, 0)),
         )
         ctrl_state, _ = ctrl_update(carry.ctrl_state, g, sim_time, stats)
@@ -559,14 +574,14 @@ def make_mode_prelude_and_tails(
         (remaining, staleness, worker_params, gsum, ssum, smax, tau_sum, _), _ = (
             jax.lax.scan(inner, init, jnp.arange(n_slots))
         )
-        g = jax.tree.map(lambda x: x / kf, gsum)
+        g = jax.tree.map(lambda x: x * (1.0 / kf), gsum)
         params, opt_state = apply_update(carry.params, g, carry.opt_state)
         params = hold_if_dead(params, carry.params, remaining)
         t_iter = tau_sum if comm_time is None else tau_sum + comm_time(k)
         sim_time = carry.sim_time + t_iter
         stats = ExecStats(
             arrivals=jnp.asarray(k, jnp.int32),
-            mean_staleness=ssum.astype(jnp.float32) / kf,
+            mean_staleness=ssum.astype(jnp.float32) * (1.0 / kf),
             max_staleness=smax,
         )
         ctrl_state, _ = ctrl_update(carry.ctrl_state, g, sim_time, stats)

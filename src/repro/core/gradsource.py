@@ -53,7 +53,26 @@ __all__ = [
     "SourceFns",
     "GradSource",
     "PerExampleSource",
+    "lane_data",
 ]
+
+
+def lane_data(data: Any, n_lanes: int) -> Any:
+    """The source's data pytree broadcast along a leading lane axis.
+
+    Both engines vmap their lanes (replicas; flattened cell x replica lanes
+    in the sweep) over this with ``in_axes=0`` and hand it to
+    ``build_stale``.  With one shared copy, the stale gradients' per-slot
+    ``X_i @ w_i`` becomes, per slot, one product whose free dimension is the
+    lane count, and XLA's CPU kernels round that product differently for
+    different lane counts.  With per-lane data each lane's products are a
+    batch of independent same-shape products, so a lane's bits do not
+    depend on how many lanes (or devices) share the program — the bitwise
+    sweep-vs-looped contract across grid sizes and mesh shapes.  The sync
+    closures keep the shared copy: their products are lane-count invariant
+    and several times faster that way.
+    """
+    return jax.tree.map(lambda a: jnp.broadcast_to(a, (n_lanes,) + a.shape), data)
 
 
 class SourceFns(NamedTuple):
@@ -137,7 +156,8 @@ class PerExampleSource:
         grad = jax.grad(step_loss)
 
         def eval_loss(params):
-            return jnp.mean(loss(params, X, y))
+            losses = loss(params, X, y)
+            return aggregation.active_worker_mean_loss(losses, n_workers, n_workers, s)
 
         def eval_loss_active(params, n_active):
             losses = loss(params, X, y)

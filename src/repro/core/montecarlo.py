@@ -65,7 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregation, execmode, faults as faultsmod
-from repro.core.gradsource import GradSource, PerExampleSource
+from repro.core.gradsource import GradSource, PerExampleSource, lane_data
 from repro.core.straggler import (
     StragglerModel,
     WorkerFleet,
@@ -235,6 +235,27 @@ def clear_program_cache() -> None:
     _N_TRACES = 0
 
 
+def _vmap_lanes(run_lane):
+    """``run_all(params0, data, keys, n_active)``: ``run_lane(params0, data,
+    lane_data, key, n_active)`` vmapped over the replica keys.  ``data`` is
+    shared by every lane; ``lane_data`` is each lane's own copy
+    (``gradsource.lane_data``), which the stale-gradient closures use — the
+    sweep engine lays its lanes out the same way."""
+
+    def run_all(params0, data, keys, n_active_arg=None):
+        # A lone replica runs as a pair (its key twice) and keeps lane 0:
+        # XLA drops size-1 batch dimensions, which would hand a one-lane
+        # program different (differently rounding) product kernels.
+        n = keys.shape[0]
+        lanes = keys if n > 1 else jnp.concatenate([keys, keys])
+        out = jax.vmap(run_lane, in_axes=(None, None, 0, 0, None))(
+            params0, data, lane_data(data, lanes.shape[0]), lanes, n_active_arg
+        )
+        return jax.tree.map(lambda a: a[:n], out)
+
+    return run_all
+
+
 def _build_program(
     source: GradSource,
     n_workers: int,
@@ -259,9 +280,10 @@ def _build_program(
         n_knots = len(straggler.schedule.times) if straggler.schedule else 0
         sched_np = pack_schedule(straggler.schedule, max(1, n_knots))
 
-    def run_all(params0, data, keys, n_active_arg=None):
+    def run_lane(params0, data, lane_data, replica_key, n_active_arg=None):
         global _N_TRACES
         _N_TRACES += 1  # Python side effect: fires once per trace, never per run
+        del lane_data  # the sync program has no stale gradients
         fns = source.build(data, n_workers)
         grad_fn = fns.grad
 
@@ -306,7 +328,7 @@ def _build_program(
             k = carry.ctrl_state.k if hasattr(carry.ctrl_state, "k") else carry.ctrl_state[0]
             mask, t_iter = draw(sub, carry.sim_time, k)
             g = grad_fn(carry.params, mask, k)
-            params = jax.tree.map(lambda p, gi: p - eta * gi, carry.params, g)
+            params = execmode.sgd_update(carry.params, g, eta)
             sim_time = carry.sim_time + t_iter
             ctrl_state, _ = controller.update(carry.ctrl_state, g, sim_time)
             return _Carry(params, ctrl_state, sim_time, new_key), k
@@ -344,9 +366,9 @@ def _build_program(
                 )
             return records
 
-        return jax.vmap(run_one)(keys)
+        return run_one(replica_key)
 
-    return jax.jit(run_all)
+    return jax.jit(_vmap_lanes(run_lane))
 
 
 def _build_async_program(
@@ -399,12 +421,12 @@ def _build_async_program(
     except (TypeError, ValueError):  # builtins / exotic callables
         accepts_stats = True
 
-    def run_all(params0, data, keys, n_active_arg=None):
+    def run_lane(params0, data, lane_data, replica_key, n_active_arg=None):
         global _N_TRACES
         _N_TRACES += 1
         # build_stale goes FIRST: it emits the per-worker shard reshape at
         # the exact op position the historical inline reshape occupied.
-        stale_grad, shard_grad_at = source.build_stale(data, n_workers)
+        stale_grad, shard_grad_at = source.build_stale(lane_data, n_workers)
         fns = source.build(data, n_workers)
 
         if is_fleet:
@@ -490,9 +512,9 @@ def _build_async_program(
                 )
             return records
 
-        return jax.vmap(run_one)(keys)
+        return run_one(replica_key)
 
-    return jax.jit(run_all)
+    return jax.jit(_vmap_lanes(run_lane))
 
 
 def run_monte_carlo_source(

@@ -95,7 +95,7 @@ from repro.core.controller import (
     _tree_dot,
     _tree_zeros_like,
 )
-from repro.core.gradsource import GradSource, PerExampleSource
+from repro.core.gradsource import GradSource, PerExampleSource, lane_data
 from repro.core.montecarlo import (
     MonteCarloResult,
     _LRUProgramCache,
@@ -831,8 +831,8 @@ def _make_run_one_moded(
 ):
     """Execution-mode-aware run_one: the ``execmode.ExecCarry`` superset
     threaded through the same eval-block scaffolding, with a per-cell
-    ``lax.switch`` over the execution-mode *tails* the signature admits.
-    Under vmap the switch computes every branch and selects, so ``mode`` is
+    select over the execution-mode *tails* the signature admits.  Every
+    tail is computed and the cell's is selected, so ``mode`` is
     an ordinary traced grid leaf — the signature's modes share ONE compiled
     program and repopulating a same-signature grid never retraces.
 
@@ -847,16 +847,17 @@ def _make_run_one_moded(
     the fresh draw bit for bit), and the async tails are the SAME step code
     the looped ``run_monte_carlo(mode=...)`` traces — sweep cells stay
     bitwise-equal to the looped engine in every mode."""
-    # build_stale emits the per-worker shard reshape at the exact op position
-    # the historical inline reshape occupied (bitwise contract).
-    stale_grad, shard_grad_at = source.build_stale(data, n_workers)
     modes = sig.modes
     mode_remap = (
         None if len(modes) in (1, len(execmode.MODES))
         else jnp.asarray(_static_remap(modes, len(execmode.MODES)))
     )
 
-    def run_one(cp: _CellParams, replica_key):
+    def run_one(cp: _CellParams, replica_key, lane_data):
+        # The stale closures read the lane's own copy of the data
+        # (gradsource.lane_data); build_stale emits the per-worker shard
+        # reshape first, as the looped engine does.
+        stale_grad, shard_grad_at = source.build_stale(lane_data, n_workers)
         # Per-cell constants, hoisted out of the iteration scan: the family
         # select masks, controller predicates, and mode index are all pure
         # functions of the cell's kind leaves.
@@ -922,7 +923,16 @@ def _make_run_one_moded(
             sel_tails = tuple(tails[m] for m in modes)
 
             def one_step(carry: execmode.ExecCarry, _):
-                return jax.lax.switch(mode_local, sel_tails, carry, prelude(carry))
+                # What vmap makes of a lax.switch on a per-lane index, minus
+                # one thing: the switch would broadcast every operand to the
+                # lanes, the closed-over data too, turning the shared
+                # ``X @ w`` into a per-lane product that rounds differently
+                # from the looped engine's.
+                p = prelude(carry)
+                outs = [tail(carry, p) for tail in sel_tails]
+                return jax.tree.map(
+                    lambda *xs: jax.lax.select_n(mode_local, *xs), *outs
+                )
 
         def eval_block(carry: execmode.ExecCarry, length: int):
             carry, ks = jax.lax.scan(
@@ -1029,7 +1039,8 @@ def _build_grid_program(
                 sig,
             )
 
-        def run_one(cp: _CellParams, replica_key):
+        def run_one(cp: _CellParams, replica_key, lane_data):
+            del lane_data  # the sync program has no stale gradients
             # Per-cell constants, hoisted out of the iteration scan (pure
             # functions of the cell's kind leaves).
             fam_masks = family_select_masks(cp.strag_kinds)
@@ -1056,7 +1067,7 @@ def _build_grid_program(
                         cp.comm_alpha + cp.comm_beta * k.astype(jnp.float32)
                     )
                 g = grad_fn(carry.params, mask, k)
-                params = jax.tree.map(lambda p, gi: p - cp.eta * gi, carry.params, g)
+                params = execmode.sgd_update(carry.params, g, cp.eta)
                 sim_time = carry.sim_time + t_iter
                 ctrl_state, _ = _ctrl_update(
                     cp, carry.ctrl_state, g, sim_time, execmode.zero_stats(k),
@@ -1106,16 +1117,22 @@ def _build_grid_program(
     # historical single-vmap program, bit for bit.
     flat_spec = P(("cells", "replicas"))
 
+    def vmap_lanes(params0, data, cells, keys):
+        # Shared data for the sync closures, each lane's own copy for the
+        # stale ones (gradsource.lane_data), as in run_monte_carlo.
+        return jax.vmap(make_run_one(params0, data))(
+            cells, keys, lane_data(data, keys.shape[0])
+        )
+
     def run_grid(params0, data, cells: _CellParams, keys):
         global _N_TRACES
         _N_TRACES += 1
         if partition == "shard_map":
-            from jax.experimental.shard_map import shard_map
 
             def body(p0, d, c, k):
-                return jax.vmap(make_run_one(p0, d))(c, k)
+                return vmap_lanes(p0, d, c, k)
 
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(
@@ -1125,10 +1142,10 @@ def _build_grid_program(
                     flat_spec,
                 ),
                 out_specs=flat_spec,
-                check_rep=False,
+                check_vma=False,
             )
             return sharded(params0, data, cells, keys)
-        return jax.vmap(make_run_one(params0, data))(cells, keys)
+        return vmap_lanes(params0, data, cells, keys)
 
     # The cell-leaf and key buffers are freshly materialized inside every
     # run_sweep dispatch (never caller-owned), so donating them lets XLA
@@ -1205,7 +1222,7 @@ def run_sweep_source(
       sharding propagation partitions the whole program (the default;
       degenerates to plain vmap on one device);
     * ``"shard_map"`` — explicit per-device blocks via
-      ``jax.experimental.shard_map`` (fallback for backends where automatic
+      ``jax.shard_map`` (fallback for backends where automatic
       propagation misbehaves);
     * ``"none"`` — no device placement (single-device debugging).
 
@@ -1329,6 +1346,12 @@ def run_sweep_source(
     # one axis over ("cells", "replicas") hands each device a contiguous
     # equal lane block.
     Gp, Rp = G + (-G) % mc, R + (-R) % mr
+    if Gp * Rp == mc * mr:
+        # One lane per device: pad the replicas once more so every device
+        # holds at least two (XLA drops size-1 batch dimensions, which
+        # would hand a one-lane program differently rounding kernels —
+        # run_monte_carlo pads a lone replica the same way).
+        Rp += mr
     padded_cells = jax.tree.map(
         lambda a: np.concatenate(
             [np.asarray(a), np.zeros((Gp - G,) + a.shape[1:], a.dtype)]

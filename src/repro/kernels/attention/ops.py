@@ -1,9 +1,9 @@
 """jit'd public wrapper for the flash-attention kernel.
 
 Accepts the model's (B, T, H, hd) layout, transposes to the kernel's
-(B, H, T, hd), picks MXU-aligned block sizes, and falls back to interpret
-mode automatically off-TPU (the kernel body then runs as pure Python/jnp on
-CPU — bit-accurate for testing)."""
+(B, H, T, hd) and picks MXU-aligned block sizes.  ``interpret=True`` runs
+the kernel body as pure Python/jnp (how the CPU tests validate it); the
+default compiles it for the TPU, so no backend is ever silently swapped."""
 
 from __future__ import annotations
 
@@ -15,11 +15,9 @@ import jax.numpy as jnp
 from repro.kernels.attention.kernel import flash_attention_bhtd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k"))
+@functools.partial(
+    jax.jit, static_argnames=("causal", "window", "block_q", "block_k", "interpret")
+)
 def flash_attention(
     q: jax.Array,  # (B, T, H, hd)
     k: jax.Array,  # (B, S, KV, hd)
@@ -29,6 +27,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
+    interpret: bool = False,
 ) -> jax.Array:
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
@@ -41,6 +40,6 @@ def flash_attention(
         window=window,
         block_q=block_q,
         block_k=block_k,
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )
     return jnp.transpose(out, (0, 2, 1, 3))

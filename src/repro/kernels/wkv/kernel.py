@@ -28,7 +28,7 @@ def _wkv_kernel(
     k_ref,  # (1, C, K)
     v_ref,  # (1, C, V)
     w_ref,  # (1, C, K)
-    u_ref,  # (1, K)
+    u_ref,  # (1, 1, K)
     s0_ref,  # (1, K, V)
     y_ref,  # (1, C, V)
     sT_ref,  # (1, K, V)
@@ -46,11 +46,19 @@ def _wkv_kernel(
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     w = w_ref[0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)
+    u = u_ref[0].astype(jnp.float32)  # (1, K)
     s = s_scr[...]
 
     logw = jnp.log(jnp.maximum(w, 1e-20))
-    li = jnp.cumsum(logw, axis=0)  # inclusive (C, K)
+    # Inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum lowering); HIGHEST keeps it at f32 accuracy.
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (col <= row).astype(jnp.float32)
+    li = jax.lax.dot_general(
+        tril, logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # inclusive (C, K)
     le = li - logw  # exclusive
     lt = li[chunk - 1]  # (K,) chunk-total log decay
 
@@ -102,7 +110,7 @@ def _wkv_kernel(
     )
 
     # current-token bonus: u-weighted diagonal
-    bonus = jnp.sum(r * u[None, :] * k, axis=1, keepdims=True)  # (C, 1)
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)  # (C, 1)
     y = y + bonus * v
 
     # state update: S' = exp(lt) S + sum_tau exp(lt - li_tau) k_tau v_tau^T
@@ -141,7 +149,9 @@ def wkv6_bhtk(
             pl.BlockSpec((1, chunk, kdim), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, vdim), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, kdim), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, kdim), lambda b, c: (b % n_heads, 0)),
+            # u as (H, 1, K): a (1, 1, K) block keeps the last two block
+            # dims equal to the array's, as the TPU tiling requires.
+            pl.BlockSpec((1, 1, kdim), lambda b, c: (b % n_heads, 0, 0)),
             pl.BlockSpec((1, kdim, vdim), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
@@ -154,5 +164,5 @@ def wkv6_bhtk(
         ],
         scratch_shapes=[pltpu.VMEM((kdim, vdim), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(n_heads, 1, kdim), s0)
     return y, s_final

@@ -1,5 +1,6 @@
-"""jit'd public wrapper for the wkv6 kernel: model layout (B,T,H,K) in/out,
-interpret-mode fallback off-TPU."""
+"""jit'd public wrapper for the wkv6 kernel: model layout (B,T,H,K) in/out.
+``interpret=True`` runs the kernel body as jnp (the CPU tests); the default
+compiles it for the TPU."""
 
 from __future__ import annotations
 
@@ -12,11 +13,7 @@ import jax.numpy as jnp
 from repro.kernels.wkv.kernel import wkv6_bhtk
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("chunk",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv6(
     r: jax.Array,  # (B, T, H, K)
     k: jax.Array,
@@ -26,6 +23,7 @@ def wkv6(
     s0: Optional[jax.Array] = None,  # (B, H, K, V)
     *,
     chunk: int = 128,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     b, t, h, kdim = r.shape
     vdim = v.shape[-1]
@@ -47,7 +45,7 @@ def wkv6(
     y, s_final = wkv6_bhtk(
         fold(r), fold(k), fold(v), fold(w),
         u, s0.reshape(b * h, kdim, vdim),
-        n_heads=h, chunk=chunk, interpret=not _on_tpu(),
+        n_heads=h, chunk=chunk, interpret=interpret,
     )
     y = jnp.transpose(y.reshape(b, h, t, vdim), (0, 2, 1, 3))
     return y, s_final.reshape(b, h, kdim, vdim)

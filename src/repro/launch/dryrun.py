@@ -144,9 +144,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, *,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.4.38 wraps the dict in a list
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     result: Dict[str, Any] = {
         "arch": arch,
         "shape": shape_name,

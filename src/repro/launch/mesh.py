@@ -10,6 +10,16 @@ sees the 512 placeholder devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.  Explicit axes (the
+    default) reject ``with_sharding_constraint`` on the activation layouts
+    and the NamedSharding placement the sweep dispatch relies on; this repo
+    pins layouts by constraint and lets XLA propagate the rest."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def sweep_mesh_shape(n_devices: int, n_cells: int, n_replicas: int) -> tuple[int, int]:
@@ -47,19 +57,19 @@ def make_sweep_mesh(
         devices = jax.devices()
     devices = list(devices)
     mc, mr = sweep_mesh_shape(len(devices), n_cells, n_replicas)
-    return jax.make_mesh((mc, mr), ("cells", "replicas"), devices=devices)
+    return _auto_mesh((mc, mr), ("cells", "replicas"), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Degenerate 1-device mesh with the production axis names — used by CPU
     integration tests so the same sharded code paths run unchanged."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple:

@@ -252,6 +252,10 @@ def _run_simulation(args):
 
 
 def main(argv=None):
+    """Parse ``argv`` and train (or ``--simulate``).  An LM run returns
+    ``{"compile_s": ..., "steps": [per-step metric dicts]}``: each step's
+    ``ce``, ``k``, simulated ``sim_time``/``iter_time`` and ``step_s``, the
+    step's wall time to ``block_until_ready``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true",
@@ -354,11 +358,6 @@ def main(argv=None):
                          "engine's (cells, replicas) mesh alike — then span "
                          "every process's devices; coordinator/rank come "
                          "from the cluster environment")
-    ap.add_argument("--cache-dir", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(repro.core.cache): cold starts load compiled "
-                         "executables from disk instead of re-running XLA; "
-                         "also honored via REPRO_COMPILATION_CACHE_DIR")
     args = ap.parse_args(argv)
 
     # Both must happen before anything touches jax device state or compiles:
@@ -368,10 +367,7 @@ def main(argv=None):
         jax.distributed.initialize()
     from repro.core import cache as cache_lib
 
-    if args.cache_dir:
-        cache_lib.enable_persistent_cache(args.cache_dir)
-    else:
-        cache_lib.maybe_enable_from_env()
+    cache_lib.setup_compilation_cache()
 
     if args.simulate:
         return _run_simulation(args)
@@ -427,9 +423,15 @@ def main(argv=None):
             start = latest
             print(f"restored step {latest} from {args.ckpt_dir}")
 
+    # The step is compiled ahead of the loop so compile time is reported as
+    # set-up and every timed step is a warm one.  Each step is timed to the
+    # moment its outputs exist on the device (block_until_ready).
+    steps = []
+    compile_s = None
     with mesh, activation_sharding(shard_lib.activation_resolver(mesh)):
         jitted = jax.jit(train_step, donate_argnums=(0,))
-        t0 = time.time()
+        step_fn = None
+        t_start = time.perf_counter()
         for step in range(start, args.steps):
             tokens, targets = data.batch_at(step)
             batch = {"tokens": tokens, "targets": targets}
@@ -440,21 +442,37 @@ def main(argv=None):
                 batch["frames"] = jnp.zeros(
                     (args.batch, cfg.encoder_frames, cfg.d_model), jnp.float32)
             key, sub = jax.random.split(key)
-            state, metrics = jitted(state, batch, sub)
+            if step_fn is None:
+                t0 = time.perf_counter()
+                step_fn = jitted.lower(state, batch, sub).compile()
+                compile_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
+            state, metrics = step_fn(state, batch, sub)
+            jax.block_until_ready((state, metrics))
+            record = {
+                "step": step,
+                "ce": float(metrics["ce"]),
+                "k": int(metrics["k"]),
+                "sim_time": float(metrics["sim_time"]),
+                "iter_time": float(metrics["iter_time"]),
+                "step_s": time.perf_counter() - t0,
+            }
+            steps.append(record)
             if step % args.log_every == 0 or step == args.steps - 1:
                 print(json.dumps({
                     "step": step,
-                    "ce": round(float(metrics["ce"]), 4),
-                    "k": int(metrics["k"]),
-                    "sim_time": round(float(metrics["sim_time"]), 2),
-                    "iter_time": round(float(metrics["iter_time"]), 3),
-                    "wall_s": round(time.time() - t0, 1),
+                    "ce": round(record["ce"], 4),
+                    "k": record["k"],
+                    "sim_time": round(record["sim_time"], 2),
+                    "iter_time": round(record["iter_time"], 3),
+                    "wall_s": round(time.perf_counter() - t_start, 1),
                 }), flush=True)
             if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
                 checkpoint.save(args.ckpt_dir, step + 1, state)
     if args.ckpt_dir:
         checkpoint.save(args.ckpt_dir, args.steps, state)
         print(f"saved final checkpoint at step {args.steps}")
+    return {"compile_s": compile_s, "steps": steps}
 
 
 if __name__ == "__main__":

@@ -6,8 +6,9 @@ Three layers of protection:
      routed through ``PerExampleSource``) must reproduce the pre-refactor
      engine's trajectories BITWISE for all five registered controllers in all
      three execution modes.  The goldens (tests/goldens/quadratic_mc.npz)
-     were generated from the engine before the gradient source became
-     pluggable — see tests/goldens/gen_quadratic_goldens.py.
+     were first generated from the engine before the gradient source became
+     pluggable, and regenerated under jax 0.9.0, whose PRNG streams differ —
+     see tests/goldens/gen_quadratic_goldens.py.
   2. **Wrapper == source** — calling the source-level entry points directly
      with ``PerExampleSource`` is the same computation as the historical
      signatures, bitwise, in both engines.

@@ -197,14 +197,41 @@ def test_inactive_inf_slots_rank_past_n_active_both_paths(n):
     assert np.all(mask[n_active:] == 0) and mask.sum() == n_active
 
 
+def _pairwise_sum(a):
+    """float32 numpy reference of aggregation.ordered_sum (last axis)."""
+    a = np.asarray(a, np.float32)
+    width = 1 << max(a.shape[-1] - 1, 0).bit_length()
+    a = np.concatenate([a, np.zeros(a.shape[:-1] + (width - a.shape[-1],), np.float32)], -1)
+    while a.shape[-1] > 1:
+        half = a.shape[-1] // 2
+        a = a[..., :half] + a[..., half:]
+    return a[..., 0]
+
+
 def test_active_worker_mean_loss_full_grid_is_bitwise_mean():
     losses = jax.random.normal(jax.random.PRNGKey(0), (24,)) ** 2
     full = active_worker_mean_loss(losses, jnp.asarray(6, jnp.int32), 6, 4)
-    assert np.array_equal(np.asarray(full), np.asarray(jnp.mean(losses)))
+    # the fixed-order mean: pairwise within shards, then across shards
+    want = _pairwise_sum(_pairwise_sum(np.asarray(losses).reshape(6, 4))) * np.float32(1 / 24)
+    assert np.array_equal(np.asarray(full), want)
+    # a traced all-active count gives the bits of the looped engine's
+    # homogeneous eval (n_active a Python int, the select folded away)
+    homo = jax.jit(lambda l: active_worker_mean_loss(l, 6, 6, 4))(losses)
+    assert np.array_equal(np.asarray(full), np.asarray(homo))
+    np.testing.assert_allclose(float(full), float(jnp.mean(losses)), rtol=1e-6)
     # masked form averages exactly the first n_active shards
     part = active_worker_mean_loss(losses, jnp.asarray(2, jnp.int32), 6, 4)
     np.testing.assert_allclose(float(part), float(jnp.mean(losses[:8])), rtol=1e-6)
 
+
+
+@pytest.mark.parametrize("n", [1, 5, 20, 400])
+def test_ordered_sum_is_the_pairwise_sum_at_any_lane_count(n):
+    x = jax.random.normal(jax.random.PRNGKey(n), (48, n)) * 100.0
+    want = _pairwise_sum(np.asarray(x))
+    for lanes in (2, 16, 48):
+        got = jax.jit(jax.vmap(aggregation.ordered_sum))(x[:lanes])
+        assert np.array_equal(np.asarray(got), want[:lanes]), lanes
 
 # ----------------------------- the acceptance invariants, engine vs engine
 

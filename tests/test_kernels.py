@@ -35,7 +35,8 @@ def test_flash_attention_matches_ref(shape, dtype):
     q = jax.random.normal(ks[0], (b, t, h, hd), dtype)
     k = jax.random.normal(ks[1], (b, s, kv, hd), dtype)
     v = jax.random.normal(ks[2], (b, s, kv, hd), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, window=window, block_q=64, block_k=64,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
@@ -51,7 +52,8 @@ def test_flash_attention_block_shape_invariance(bq, bk):
     q = jax.random.normal(ks[0], (b, t, h, hd))
     k = jax.random.normal(ks[1], (b, t, h, hd))
     v = jax.random.normal(ks[2], (b, t, h, hd))
-    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -63,7 +65,8 @@ def test_flash_attention_first_token_attends_only_to_itself():
     q = jax.random.normal(ks[0], (b, t, h, hd))
     k = jax.random.normal(ks[1], (b, t, h, hd))
     v = jax.random.normal(ks[2], (b, t, h, hd))
-    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(v[:, 0]), atol=1e-5)
 
 
@@ -103,7 +106,7 @@ def test_wkv_kernel_matches_naive(shape):
     b, t, h, k, v_dim = shape
     r, kk, vv, w, u, s0 = _wkv_inputs(b, t, h, k, v_dim)
     y_ref, s_ref = _wkv_naive(r, kk, vv, w, u, s0)
-    y, s = wkv6(r, kk, vv, w, u, s0, chunk=32)
+    y, s = wkv6(r, kk, vv, w, u, s0, chunk=32, interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=5e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=5e-4, rtol=1e-3)
 
@@ -112,7 +115,7 @@ def test_wkv_kernel_matches_naive(shape):
 def test_wkv_kernel_chunk_invariance(chunk):
     r, kk, vv, w, u, s0 = _wkv_inputs(2, 128, 2, 16, 16)
     y_ref, s_ref = _wkv_naive(r, kk, vv, w, u, s0)
-    y, s = wkv6(r, kk, vv, w, u, s0, chunk=chunk)
+    y, s = wkv6(r, kk, vv, w, u, s0, chunk=chunk, interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), atol=1e-3, rtol=2e-3)
 
@@ -120,7 +123,7 @@ def test_wkv_kernel_chunk_invariance(chunk):
 def test_wkv_chunk_over_64_rejected():
     r, kk, vv, w, u, s0 = _wkv_inputs(1, 128, 1, 8, 8)
     with pytest.raises(ValueError, match="chunk must be <= 64"):
-        wkv6(r, kk, vv, w, u, s0, chunk=128)
+        wkv6(r, kk, vv, w, u, s0, chunk=128, interpret=True)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -129,7 +132,7 @@ def test_wkv_kernel_dtypes(dtype):
     y_ref, _ = _wkv_naive(r, kk, vv, w, u, s0)
     y, _ = wkv6(
         r.astype(dtype), kk.astype(dtype), vv.astype(dtype), w.astype(jnp.float32),
-        u, s0, chunk=32,
+        u, s0, chunk=32, interpret=True,
     )
     tol = 5e-2 if dtype == jnp.bfloat16 else 5e-4
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref), atol=tol, rtol=0.05)
@@ -144,7 +147,7 @@ def test_wkv_strong_decay_stability():
 
     r, kk, vv, w, u, s0 = _wkv_inputs(1, 128, 1, 8, 8, decay_scale=1.0)
     y_ref, s_ref = _wkv_naive(r, kk, vv, w, u, s0)
-    y, s = wkv6(r, kk, vv, w, u, s0, chunk=64)
+    y, s = wkv6(r, kk, vv, w, u, s0, chunk=64, interpret=True)
     assert bool(jnp.all(jnp.isfinite(y)))
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-3, rtol=5e-3)
     yj, sj = wkv6_chunked(r, kk, vv, w, u, s0, chunk=64)

@@ -24,6 +24,7 @@ import sys
 
 import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro import shardctx
 from repro.launch import mesh as mesh_lib
@@ -87,7 +88,8 @@ def test_sweep_mesh_context_install_and_restore():
 
 
 def test_sweep_mesh_context_rejects_wrong_axes():
-    bad = jax.make_mesh((1, 1), ("data", "model"))
+    bad = jax.make_mesh((1, 1), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
     with pytest.raises(ValueError, match="cells"):
         with shardctx.sweep_mesh(bad):
             pass
@@ -154,6 +156,8 @@ def test_check_bench_cold_cache_rules():
 
 _PODSCALE_SCRIPT = """
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
+AUTO2 = (AxisType.Auto,) * 2
 assert jax.local_device_count() == 8, jax.local_device_count()
 from repro import shardctx
 from repro.core.faults import byzantine_plan
@@ -215,7 +219,7 @@ for part in ("auto", "shard_map"):
 # all three padding regimes are exercised.  Shapes alternate between the
 # shardctx context and the explicit mesh= argument to pin both plumbings.
 for i, shape in enumerate([(1, 8), (2, 4), (8, 1)]):
-    mesh = jax.make_mesh(shape, ("cells", "replicas"))
+    mesh = jax.make_mesh(shape, ("cells", "replicas"), axis_types=AUTO2)
     if i % 2 == 0:
         with shardctx.sweep_mesh(mesh):
             res = run_sweep(loss, w0, data.X, data.y, cases=cases, **kw)
@@ -224,7 +228,7 @@ for i, shape in enumerate([(1, 8), (2, 4), (8, 1)]):
     check(res, f"mesh{shape}")
 
 # shard_map on a genuinely 2-D decomposition
-mesh = jax.make_mesh((2, 4), ("cells", "replicas"))
+mesh = jax.make_mesh((2, 4), ("cells", "replicas"), axis_types=AUTO2)
 check(run_sweep(loss, w0, data.X, data.y, cases=cases, mesh=mesh,
                 partition="shard_map", **kw), "mesh(2, 4)/shard_map")
 print("PODSCALE_OK")
@@ -247,9 +251,9 @@ def test_sweep_2d_mesh_bitwise_across_shapes_forced_8_devices():
 
 _CACHE_SCRIPT = """
 import json, sys, time
-cache_dir, iters = sys.argv[1], int(sys.argv[2])
+iters = int(sys.argv[1])
 from repro.core import cache as cache_lib
-cache_lib.enable_persistent_cache(cache_dir)
+cache_dir = cache_lib.setup_compilation_cache()
 import jax, jax.numpy as jnp
 from repro.core.controller import FixedKController
 from repro.core.straggler import Exponential
@@ -257,7 +261,7 @@ from repro.core.sweep import SweepCase, run_sweep
 from repro.data import make_linreg_data
 
 data = make_linreg_data(jax.random.PRNGKey(0), m=8, d=2)
-before = cache_lib.cache_entries()
+before = cache_lib.cache_entries(cache_dir)
 t0 = time.perf_counter()
 run_sweep(lambda w, X, y: (X @ w - y) ** 2, jnp.zeros((2,)), data.X, data.y,
           n_workers=2,
@@ -265,15 +269,16 @@ run_sweep(lambda w, X, y: (X @ w - y) ** 2, jnp.zeros((2,)), data.X, data.y,
                            Exponential(rate=1.0), 0.01)],
           num_iters=iters, key=jax.random.PRNGKey(0), n_replicas=1,
           eval_every=2)
-print(json.dumps({"added": cache_lib.cache_entries() - before,
+print(json.dumps({"added": cache_lib.cache_entries(cache_dir) - before,
                   "cold_s": time.perf_counter() - t0}))
 """
 
 
 def _cache_probe(cache_dir, iters):
-    proc = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT,
-                           cache_dir, str(iters)],
-                          env=_sub_env(), capture_output=True, text=True,
+    env = _sub_env()
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    proc = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT, str(iters)],
+                          env=env, capture_output=True, text=True,
                           timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])

@@ -56,7 +56,8 @@ def test_flash_attention_property(t, h, g, hd, seed):
     q = jax.random.normal(ks[0], (1, t, h, hd))
     k = jax.random.normal(ks[1], (1, t, kv, hd))
     v = jax.random.normal(ks[2], (1, t, kv, hd))
-    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
 
@@ -80,9 +81,11 @@ def _wkv_inputs(b, t, h, k, v_dim, seed=0, decay_scale=0.5):
 def test_wkv_property_state_consistency(seed, chunk):
     """Splitting the sequence and carrying state == one pass (renewal property)."""
     r, kk, vv, w, u, s0 = _wkv_inputs(1, 64, 2, 8, 8, seed=seed)
-    y_all, s_all = wkv6(r, kk, vv, w, u, s0, chunk=chunk)
-    y1, s1 = wkv6(r[:, :32], kk[:, :32], vv[:, :32], w[:, :32], u, s0, chunk=chunk)
-    y2, s2 = wkv6(r[:, 32:], kk[:, 32:], vv[:, 32:], w[:, 32:], u, s1, chunk=chunk)
+    y_all, s_all = wkv6(r, kk, vv, w, u, s0, chunk=chunk, interpret=True)
+    y1, s1 = wkv6(r[:, :32], kk[:, :32], vv[:, :32], w[:, :32], u, s0, chunk=chunk,
+                  interpret=True)
+    y2, s2 = wkv6(r[:, 32:], kk[:, 32:], vv[:, 32:], w[:, 32:], u, s1, chunk=chunk,
+                  interpret=True)
     np.testing.assert_allclose(
         np.asarray(jnp.concatenate([y1, y2], 1)), np.asarray(y_all), atol=1e-3, rtol=2e-3
     )
