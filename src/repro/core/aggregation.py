@@ -221,11 +221,13 @@ def fastest_k_mask_time(times: jax.Array, k: jax.Array) -> Tuple[jax.Array, jax.
     statistic.  This is THE per-iteration hot-path primitive: both
     ``run_monte_carlo`` (via ``fastest_k_draw``) and the sweep engine (which
     samples through its packed-parameter ``lax.switch``) call it, so the two
-    engines stay bitwise-identical by construction.
+    engines stay bitwise-identical by construction.  Its device time is
+    the ``repro.ranks`` scope.
     """
-    ranks = worker_ranks(times)
-    mask = (ranks < k).astype(times.dtype)
-    return mask, _time_from_ranks(ranks, times, k, None)
+    with jax.named_scope("repro.ranks"):
+        ranks = worker_ranks(times)
+        mask = (ranks < k).astype(times.dtype)
+        return mask, _time_from_ranks(ranks, times, k, None)
 
 
 def fastest_k_draw(

@@ -32,6 +32,11 @@ safe to share across heterogeneous hosts; stale entries are simply never hit.
 The entry points (``launch/train.py``, ``chip_smoke.py``, the benchmark
 CLIs) call ``setup_compilation_cache()`` before their first compile; nothing
 calls it at import, so library users and the tests keep jax's defaults.
+
+``setup_compilation_cache()`` also starts the process-wide compile counter
+that ``compile_stats()`` reads, from jax's own monitoring events: a retrace
+or recompile shows there even where the disk cache hides it from
+``cache_entries``.
 """
 
 from __future__ import annotations
@@ -41,7 +46,8 @@ from typing import Optional
 
 import jax
 
-__all__ = ["ENV_VAR", "DEFAULT_DIR", "setup_compilation_cache", "cache_entries"]
+__all__ = ["ENV_VAR", "DEFAULT_DIR", "setup_compilation_cache", "cache_entries",
+           "compile_stats"]
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = os.path.join(
@@ -49,6 +55,39 @@ DEFAULT_DIR = os.path.join(
         os.path.abspath(__file__))))),
     ".jax_cache",
 )
+
+
+# jax.monitoring event -> compile_stats() key.  Backend compiles count every
+# executable XLA was asked for, those the persistent cache served included.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "traces",
+    "/jax/core/compile/backend_compile_duration": "compiles",
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+}
+_COMPILE_COUNTS = dict.fromkeys(_COMPILE_EVENTS.values(), 0)
+_counting = False
+
+
+def _count_compile_event(event: str, *_, **__) -> None:
+    key = _COMPILE_EVENTS.get(event)
+    if key is not None:
+        _COMPILE_COUNTS[key] += 1
+
+
+def _count_compiles() -> None:
+    global _counting
+    if not _counting:
+        jax.monitoring.register_event_listener(_count_compile_event)
+        jax.monitoring.register_event_duration_secs_listener(_count_compile_event)
+        _counting = True
+
+
+def compile_stats() -> dict:
+    """Process-wide counts since ``setup_compilation_cache()``: jaxpr
+    ``traces``, backend ``compiles`` (every executable requested of XLA) and
+    the persistent-cache ``cache_hits`` among them.  A warm loop adds 0 to
+    each; the difference of two snapshots says which steps recompiled."""
+    return dict(_COMPILE_COUNTS)
 
 
 def setup_compilation_cache() -> str:
@@ -61,8 +100,10 @@ def setup_compilation_cache() -> str:
     the small auxiliary executables and leave a fresh process still paying
     a compile.  Also enables the XLA-level sub-caches (autotune results
     etc.) where the backend supports them.  Idempotent; must run before the
-    process's first compile to take effect.
+    process's first compile to take effect.  Starts ``compile_stats()``'s
+    counter.
     """
+    _count_compiles()
     cache_dir = os.environ.get(ENV_VAR) or DEFAULT_DIR
     os.makedirs(cache_dir, exist_ok=True)
     if not os.environ.get(ENV_VAR):

@@ -46,6 +46,18 @@ The step functions here are **shared verbatim** by ``repro.core.montecarlo``
 (class-based leaves, the per-cell ground truth) and ``repro.core.sweep``
 (traced grid leaves) — the construction that keeps the two engines
 bitwise-identical per cell.
+
+Each part of a step runs under a ``jax.named_scope``, so a profile of any
+program built from these functions (the looped engine, the sweep's grid
+program, the LM train step) attributes device time by part:
+``repro.sampler`` (key split, straggler draw, renewal clocks),
+``repro.ranks`` (``aggregation.fastest_k_mask_time``), ``repro.grad`` (the
+gradient closures, forward and backward), ``repro.aggregate`` (gradient
+faults and the robust aggregators), ``repro.update`` (SGD or the plugged
+optimizer), ``repro.controller`` (the k update) and ``repro.async_state``
+(the kasync/kbatch snapshot, clock and staleness bookkeeping).  The engines
+add ``repro.eval`` around their loss evaluations.  A scope changes the
+compiled program's metadata only, never its arithmetic.
 """
 
 from __future__ import annotations
@@ -160,8 +172,22 @@ def sgd_update(params, g, eta):
     and the two orders round differently.  With the barrier both engines
     apply the same multiply to the same gradient bits.
     """
-    g = jax.lax.optimization_barrier(g)
-    return jax.tree.map(lambda pa, gi: pa - eta * gi, params, g)
+    with jax.named_scope("repro.update"):
+        g = jax.lax.optimization_barrier(g)
+        return jax.tree.map(lambda pa, gi: pa - eta * gi, params, g)
+
+
+def _in_scope(name: str, fn: Callable | None) -> Callable | None:
+    """``fn`` with every operation it traces under the named scope ``name``
+    (``None`` stays ``None``)."""
+    if fn is None:
+        return None
+
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return scoped
 
 
 def _slot_bcast(mask: jax.Array, like: jax.Array) -> jax.Array:
@@ -313,6 +339,13 @@ def make_mode_prelude_and_tails(
         def apply_update(params, g, opt_state):
             return sgd_update(params, g, eta), opt_state
 
+    draw = _in_scope("repro.sampler", draw)
+    sync_grad = _in_scope("repro.grad", sync_grad)
+    stale_grad = _in_scope("repro.grad", stale_grad)
+    shard_grad_at = _in_scope("repro.grad", shard_grad_at)
+    apply_update = _in_scope("repro.update", apply_update)
+    ctrl_update = _in_scope("repro.controller", ctrl_update)
+
     has_crash = faults is not None and faults.time is not None
     has_grad_fault = faults is not None and faults.weight is not None
     has_gauss = faults is not None and faults.noise_rows is not None
@@ -354,6 +387,8 @@ def make_mode_prelude_and_tails(
             g = robust_agg(g, rows, arrive_f, k)
         return g
 
+    corrupted_grad = _in_scope("repro.aggregate", corrupted_grad)
+
     def hold_if_dead(params, old_params, remaining):
         """The zero-survivors pin: parameters hold once every clock is +inf
         (iteration time is already +inf via the saturated order statistic)."""
@@ -365,13 +400,14 @@ def make_mode_prelude_and_tails(
         )
 
     def prelude(carry: ExecCarry) -> ModePrelude:
-        new_key, sub = jax.random.split(carry.key)
-        k = ctrl_k(carry.ctrl_state)
-        remaining = renewal_remaining(
-            draw(sub, carry.sim_time), carry.pending, carry.remaining
-        )
-        if has_crash:
-            remaining = faults.time(remaining, carry.sim_time)
+        with jax.named_scope("repro.sampler"):
+            new_key, sub = jax.random.split(carry.key)
+            k = ctrl_k(carry.ctrl_state)
+            remaining = renewal_remaining(
+                draw(sub, carry.sim_time), carry.pending, carry.remaining
+            )
+            if has_crash:
+                remaining = faults.time(remaining, carry.sim_time)
         # The sync hot-path primitive, read over residual clocks: arrival
         # set = the K smallest clocks, event duration = the K-th one.  (For
         # sync cells the clocks ARE the fresh draw — pending is never set.)
@@ -607,7 +643,11 @@ def make_mode_prelude_and_tails(
             k,
         )
 
-    return prelude, (sync_tail, kasync_tail, kbatch_tail)
+    return prelude, (
+        sync_tail,
+        _in_scope("repro.async_state", kasync_tail),
+        _in_scope("repro.async_state", kbatch_tail),
+    )
 
 
 def make_mode_steps(

@@ -70,9 +70,13 @@ def lane_data(data: Any, n_lanes: int) -> Any:
     depend on how many lanes (or devices) share the program — the bitwise
     sweep-vs-looped contract across grid sizes and mesh shapes.  The sync
     closures keep the shared copy: their products are lane-count invariant
-    and several times faster that way.
+    and several times faster that way.  Its device time is async state
+    (``repro.async_state``).
     """
-    return jax.tree.map(lambda a: jnp.broadcast_to(a, (n_lanes,) + a.shape), data)
+    with jax.named_scope("repro.async_state"):
+        return jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (n_lanes,) + a.shape), data
+        )
 
 
 class SourceFns(NamedTuple):

@@ -323,14 +323,17 @@ def _build_program(
             mean_loss = fns.eval_loss
 
         def one_step(carry: _Carry, _):
-            new_key, sub = jax.random.split(carry.key)
             # k comes from the *previous* controller state (decided before the step).
             k = carry.ctrl_state.k if hasattr(carry.ctrl_state, "k") else carry.ctrl_state[0]
-            mask, t_iter = draw(sub, carry.sim_time, k)
-            g = grad_fn(carry.params, mask, k)
+            with jax.named_scope("repro.sampler"):  # the ranks scope their own
+                new_key, sub = jax.random.split(carry.key)
+                mask, t_iter = draw(sub, carry.sim_time, k)
+            with jax.named_scope("repro.grad"):
+                g = grad_fn(carry.params, mask, k)
             params = execmode.sgd_update(carry.params, g, eta)
             sim_time = carry.sim_time + t_iter
-            ctrl_state, _ = controller.update(carry.ctrl_state, g, sim_time)
+            with jax.named_scope("repro.controller"):
+                ctrl_state, _ = controller.update(carry.ctrl_state, g, sim_time)
             return _Carry(params, ctrl_state, sim_time, new_key), k
 
         def eval_block(carry: _Carry, length: int):
@@ -342,7 +345,9 @@ def _build_program(
             carry, ks = jax.lax.scan(
                 one_step, carry, None, length=length, unroll=min(unroll, length)
             )
-            return carry, (carry.sim_time, mean_loss(carry.params), ks[-1])
+            with jax.named_scope("repro.eval"):
+                loss = mean_loss(carry.params)
+            return carry, (carry.sim_time, loss, ks[-1])
 
         def run_one(replica_key):
             carry = _Carry(
@@ -491,7 +496,9 @@ def _build_async_program(
                 lambda c, _: one_step(c), carry, None,
                 length=length, unroll=min(unroll, length),
             )
-            return carry, (carry.sim_time, mean_loss(carry.params), ks[-1])
+            with jax.named_scope("repro.eval"):
+                loss = mean_loss(carry.params)
+            return carry, (carry.sim_time, loss, ks[-1])
 
         def run_one(replica_key):
             carry = execmode.init_exec_carry(

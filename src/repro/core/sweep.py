@@ -77,12 +77,14 @@ API sketch::
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import aggregation, execmode, faults
@@ -930,17 +932,18 @@ def _make_run_one_moded(
                 # from the looped engine's.
                 p = prelude(carry)
                 outs = [tail(carry, p) for tail in sel_tails]
-                return jax.tree.map(
-                    lambda *xs: jax.lax.select_n(mode_local, *xs), *outs
-                )
+                with jax.named_scope("repro.async_state"):
+                    return jax.tree.map(
+                        lambda *xs: jax.lax.select_n(mode_local, *xs), *outs
+                    )
 
         def eval_block(carry: execmode.ExecCarry, length: int):
             carry, ks = jax.lax.scan(
                 one_step, carry, None, length=length, unroll=min(unroll, length)
             )
-            return carry, (
-                carry.sim_time, mean_loss(carry.params, cp.n_active), ks[-1]
-            )
+            with jax.named_scope("repro.eval"):
+                loss = mean_loss(carry.params, cp.n_active)
+            return carry, (carry.sim_time, loss, ks[-1])
 
         carry = execmode.init_exec_carry(
             params0, n_workers, _ctrl_init(cp, params0, sketch_dim), replica_key
@@ -1047,41 +1050,45 @@ def _build_grid_program(
             ctrl_preds = _ctrl_preds(cp, sig.ctrl_kinds)
 
             def one_step(carry: _SweepCarry, _):
-                new_key, sub = jax.random.split(carry.key)
                 k = carry.ctrl_state.k
-                # Signature pruning: the rate-schedule drift and the
-                # comm-model adds are traced only when some cell can select
-                # them (each is a bitwise no-op for the cells that don't).
-                pm = (
-                    apply_rate_schedule(
-                        cp.strag_p, cp.sched_mode, cp.sched_leaf,
-                        cp.sched_times, cp.sched_scales, carry.sim_time,
+                with jax.named_scope("repro.sampler"):
+                    new_key, sub = jax.random.split(carry.key)
+                    # Signature pruning: the rate-schedule drift and the
+                    # comm-model adds are traced only when some cell can
+                    # select them (each is a bitwise no-op for the cells
+                    # that don't).
+                    pm = (
+                        apply_rate_schedule(
+                            cp.strag_p, cp.sched_mode, cp.sched_leaf,
+                            cp.sched_times, cp.sched_scales, carry.sim_time,
+                        )
+                        if sig.with_schedule
+                        else cp.strag_p
                     )
-                    if sig.with_schedule
-                    else cp.strag_p
-                )
-                times = sample_times_selected(fam_masks, pm, sub)
+                    times = sample_times_selected(fam_masks, pm, sub)
                 mask, t_iter = aggregation.fastest_k_mask_time(times, k)
                 if sig.with_comm:
                     t_iter = t_iter + (
                         cp.comm_alpha + cp.comm_beta * k.astype(jnp.float32)
                     )
-                g = grad_fn(carry.params, mask, k)
+                with jax.named_scope("repro.grad"):
+                    g = grad_fn(carry.params, mask, k)
                 params = execmode.sgd_update(carry.params, g, cp.eta)
                 sim_time = carry.sim_time + t_iter
-                ctrl_state, _ = _ctrl_update(
-                    cp, carry.ctrl_state, g, sim_time, execmode.zero_stats(k),
-                    sketch_dim, sig.ctrl_kinds, preds=ctrl_preds,
-                )
+                with jax.named_scope("repro.controller"):
+                    ctrl_state, _ = _ctrl_update(
+                        cp, carry.ctrl_state, g, sim_time, execmode.zero_stats(k),
+                        sketch_dim, sig.ctrl_kinds, preds=ctrl_preds,
+                    )
                 return _SweepCarry(params, ctrl_state, sim_time, new_key), k
 
             def eval_block(carry: _SweepCarry, length: int):
                 carry, ks = jax.lax.scan(
                     one_step, carry, None, length=length, unroll=min(unroll, length)
                 )
-                return carry, (
-                    carry.sim_time, mean_loss(carry.params, cp.n_active), ks[-1]
-                )
+                with jax.named_scope("repro.eval"):
+                    loss = mean_loss(carry.params, cp.n_active)
+                return carry, (carry.sim_time, loss, ks[-1])
 
             carry = _SweepCarry(
                 params=params0,
@@ -1155,6 +1162,7 @@ def _build_grid_program(
     return jax.jit(run_grid, donate_argnums=dispatch_donation())
 
 
+@functools.partial(jax.profiler.annotate_function, name="repro.sweep.run")
 def run_sweep_source(
     source: GradSource,
     params0,
@@ -1246,6 +1254,15 @@ def run_sweep_source(
     Every cell (g, r) is bitwise-equal to
     ``run_monte_carlo(..., controller=cases[g].controller, ...)``'s replica r
     with the same key.
+
+    The host work of a call shows in a profile as a ``repro.sweep.run``
+    span around the call and one child span per phase:
+    ``repro.sweep.cells`` (signature, per-cell leaves, stack),
+    ``repro.sweep.layout`` (padding, the flat lane index and its gathers),
+    ``repro.sweep.place`` (placement on the mesh), ``repro.sweep.program``
+    (program-cache lookup, with ``repro.sweep.build`` on a miss),
+    ``repro.sweep.call`` (the dispatch; a trace and compile land here) and
+    ``repro.sweep.unpad`` (slicing the padding off the outputs).
     """
     if not cases:
         raise ValueError("cases must be non-empty")
@@ -1268,156 +1285,163 @@ def run_sweep_source(
     if partition not in ("auto", "shard_map", "none"):
         raise ValueError(f"unknown partition {partition!r}")
 
-    if n_switch_slots is None:
-        n_switch_slots = max(
-            [1]
-            + [
-                len(list(c.controller.switch_times))
-                for c in cases
-                if isinstance(c.controller, ScheduleController)
-            ]
-        )
-    if n_sched_slots is None:
-        n_sched_slots = max(
-            [1]
-            + [
-                len(c.straggler.schedule.times)
-                for c in cases
-                if isinstance(c.straggler, WorkerFleet) and c.straggler.schedule
-            ]
-        )
-    # The grid's static sketch layout: every sketched cell must share one
-    # sketch_dim (it is the prev_sketch carry shape, baked into the trace).
-    sketch_dims = {
-        c.controller.sketch_dim
-        for c in cases
-        if isinstance(c.controller, SketchedPflugController)
-    }
-    if len(sketch_dims) > 1:
-        raise ValueError(
-            f"sketched cells disagree on sketch_dim ({sorted(sketch_dims)}); "
-            "one sweep supports a single static sketch layout"
-        )
-    sketch_dim = sketch_dims.pop() if sketch_dims else 1
-    # The grid's branch signature selects the program family: specialized
-    # programs trace only the branches the signature admits (cached per
-    # signature — same-signature repopulation never retraces), while
-    # specialize=False collapses every grid onto the fully-grid-agnostic
-    # signature (retaining the historical lean-program split for all-sync
-    # grids).  Either way `mode`/kind assignments stay traced leaves.
-    sig = grid_signature(cases, n_workers) if specialize else _full_signature(cases)
-    if unroll is None:
-        unroll = _auto_unroll(sig)
-    G, R = len(cases), keys.shape[0]
-    cells_np = [
-        _cell_of(c, n_workers, n_switch_slots, n_sched_slots, sketch_dim, params0)
-        for c in cases
-    ]
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *cells_np)
-
-    if partition == "none":
-        mesh = None
-        mc = mr = n_proc = 1
-    else:
-        if mesh is None:
-            from repro import shardctx
-
-            mesh = shardctx.current_sweep_mesh()
-        if mesh is None:
-            from repro.launch import mesh as mesh_lib
-
-            mesh = mesh_lib.make_sweep_mesh(G, R)
-        if tuple(mesh.axis_names) != ("cells", "replicas"):
-            raise ValueError(
-                "sweep mesh must have axes ('cells', 'replicas'), got "
-                f"{tuple(mesh.axis_names)}"
+    with TraceAnnotation("repro.sweep.cells"):
+        if n_switch_slots is None:
+            n_switch_slots = max(
+                [1]
+                + [
+                    len(list(c.controller.switch_times))
+                    for c in cases
+                    if isinstance(c.controller, ScheduleController)
+                ]
             )
-        mc, mr = mesh.shape["cells"], mesh.shape["replicas"]
-        n_proc = jax.process_count()
+        if n_sched_slots is None:
+            n_sched_slots = max(
+                [1]
+                + [
+                    len(c.straggler.schedule.times)
+                    for c in cases
+                    if isinstance(c.straggler, WorkerFleet) and c.straggler.schedule
+                ]
+            )
+        # The grid's static sketch layout: every sketched cell must share one
+        # sketch_dim (it is the prev_sketch carry shape, baked into the trace).
+        sketch_dims = {
+            c.controller.sketch_dim
+            for c in cases
+            if isinstance(c.controller, SketchedPflugController)
+        }
+        if len(sketch_dims) > 1:
+            raise ValueError(
+                f"sketched cells disagree on sketch_dim ({sorted(sketch_dims)}); "
+                "one sweep supports a single static sketch layout"
+            )
+        sketch_dim = sketch_dims.pop() if sketch_dims else 1
+        # The grid's branch signature selects the program family: specialized
+        # programs trace only the branches the signature admits (cached per
+        # signature — same-signature repopulation never retraces), while
+        # specialize=False collapses every grid onto the fully-grid-agnostic
+        # signature (retaining the historical lean-program split for all-sync
+        # grids).  Either way `mode`/kind assignments stay traced leaves.
+        sig = grid_signature(cases, n_workers) if specialize else _full_signature(cases)
+        if unroll is None:
+            unroll = _auto_unroll(sig)
+        G, R = len(cases), keys.shape[0]
+        cells_np = [
+            _cell_of(c, n_workers, n_switch_slots, n_sched_slots, sketch_dim, params0)
+            for c in cases
+        ]
+        stacked = jax.tree.map(lambda *xs: np.stack(xs), *cells_np)
 
-    # Pad each grid axis to its mesh-axis multiple; padded lanes are sliced
-    # off before results are returned.  Cells pad with EMPTY all-zero
-    # parameter rows (inert: zero-rate samplers draw +inf, n_active=0 holds
-    # all data out — and any junk they compute stays confined to their own
-    # lanes, there is no cross-lane arithmetic — never gathered copies of a
-    # real cell, so padding can't amplify real compute); replicas pad by
-    # repeating key 0.  The padded (Gp, Rp) grid then flattens CELL-MAJOR
-    # into the (Gp*Rp,) lane axis the program vmaps over, so sharding that
-    # one axis over ("cells", "replicas") hands each device a contiguous
-    # equal lane block.
-    Gp, Rp = G + (-G) % mc, R + (-R) % mr
-    if Gp * Rp == mc * mr:
-        # One lane per device: pad the replicas once more so every device
-        # holds at least two (XLA drops size-1 batch dimensions, which
-        # would hand a one-lane program differently rounding kernels —
-        # run_monte_carlo pads a lone replica the same way).
-        Rp += mr
-    padded_cells = jax.tree.map(
-        lambda a: np.concatenate(
-            [np.asarray(a), np.zeros((Gp - G,) + a.shape[1:], a.dtype)]
+    with TraceAnnotation("repro.sweep.layout"):
+        if partition == "none":
+            mesh = None
+            mc = mr = n_proc = 1
+        else:
+            if mesh is None:
+                from repro import shardctx
+
+                mesh = shardctx.current_sweep_mesh()
+            if mesh is None:
+                from repro.launch import mesh as mesh_lib
+
+                mesh = mesh_lib.make_sweep_mesh(G, R)
+            if tuple(mesh.axis_names) != ("cells", "replicas"):
+                raise ValueError(
+                    "sweep mesh must have axes ('cells', 'replicas'), got "
+                    f"{tuple(mesh.axis_names)}"
+                )
+            mc, mr = mesh.shape["cells"], mesh.shape["replicas"]
+            n_proc = jax.process_count()
+
+        # Pad each grid axis to its mesh-axis multiple; padded lanes are sliced
+        # off before results are returned.  Cells pad with EMPTY all-zero
+        # parameter rows (inert: zero-rate samplers draw +inf, n_active=0 holds
+        # all data out — and any junk they compute stays confined to their own
+        # lanes, there is no cross-lane arithmetic — never gathered copies of a
+        # real cell, so padding can't amplify real compute); replicas pad by
+        # repeating key 0.  The padded (Gp, Rp) grid then flattens CELL-MAJOR
+        # into the (Gp*Rp,) lane axis the program vmaps over, so sharding that
+        # one axis over ("cells", "replicas") hands each device a contiguous
+        # equal lane block.
+        Gp, Rp = G + (-G) % mc, R + (-R) % mr
+        if Gp * Rp == mc * mr:
+            # One lane per device: pad the replicas once more so every device
+            # holds at least two (XLA drops size-1 batch dimensions, which
+            # would hand a one-lane program differently rounding kernels —
+            # run_monte_carlo pads a lone replica the same way).
+            Rp += mr
+        padded_cells = jax.tree.map(
+            lambda a: np.concatenate(
+                [np.asarray(a), np.zeros((Gp - G,) + a.shape[1:], a.dtype)]
+            )
+            if Gp > G
+            else np.asarray(a),
+            stacked,
         )
-        if Gp > G
-        else np.asarray(a),
-        stacked,
-    )
-    padded_keys = (
-        keys[np.concatenate([np.arange(R), np.zeros(Rp - R, np.int64)])]
-        if Rp > R
-        else keys
-    )
-    cell_idx = np.repeat(np.arange(Gp), Rp)
-    rep_idx = np.tile(np.arange(Rp), Gp)
-    flat_cells = jax.tree.map(lambda a: jnp.asarray(a)[cell_idx], padded_cells)
-    flat_keys = padded_keys[rep_idx]
+        padded_keys = (
+            keys[np.concatenate([np.arange(R), np.zeros(Rp - R, np.int64)])]
+            if Rp > R
+            else keys
+        )
+        cell_idx = np.repeat(np.arange(Gp), Rp)
+        rep_idx = np.tile(np.arange(Rp), Gp)
+        flat_cells = jax.tree.map(lambda a: jnp.asarray(a)[cell_idx], padded_cells)
+        flat_keys = padded_keys[rep_idx]
 
     if mesh is not None:
         from repro.launch.sharding import place_spanning
 
-        lane_sharding = NamedSharding(mesh, P(("cells", "replicas")))
-        replicated = NamedSharding(mesh, P())
-        flat_cells = jax.tree.map(
-            lambda a: place_spanning(a, lane_sharding), flat_cells
-        )
-        flat_keys = place_spanning(flat_keys, lane_sharding)
-        params0 = jax.tree.map(lambda a: place_spanning(a, replicated), params0)
-        data = jax.tree.map(lambda a: place_spanning(a, replicated), data)
+        with TraceAnnotation("repro.sweep.place"):
+            lane_sharding = NamedSharding(mesh, P(("cells", "replicas")))
+            replicated = NamedSharding(mesh, P())
+            flat_cells = jax.tree.map(
+                lambda a: place_spanning(a, lane_sharding), flat_cells
+            )
+            flat_keys = place_spanning(flat_keys, lane_sharding)
+            params0 = jax.tree.map(lambda a: place_spanning(a, replicated), params0)
+            data = jax.tree.map(lambda a: place_spanning(a, replicated), data)
 
-    cache_key = (
-        source.cache_token(),
-        n_workers,
-        int(num_iters),
-        int(eval_every),
-        int(unroll),
-        int(n_switch_slots),
-        int(n_sched_slots),
-        int(sketch_dim),
-        partition,
-        (mc, mr, n_proc),
-        sig,
-    )
-    program = _PROGRAM_CACHE.get(cache_key)
-    if program is None:
-        program = _build_grid_program(
-            source, n_workers, num_iters, eval_every, unroll,
-            sketch_dim, partition, mesh, sig,
+    with TraceAnnotation("repro.sweep.program"):
+        cache_key = (
+            source.cache_token(),
+            n_workers,
+            int(num_iters),
+            int(eval_every),
+            int(unroll),
+            int(n_switch_slots),
+            int(n_sched_slots),
+            int(sketch_dim),
+            partition,
+            (mc, mr, n_proc),
+            sig,
         )
-        _PROGRAM_CACHE[cache_key] = program
-    times, losses, ks = program(params0, data, flat_cells, flat_keys)
+        program = _PROGRAM_CACHE.get(cache_key)
+        if program is None:
+            with TraceAnnotation("repro.sweep.build"):
+                program = _build_grid_program(
+                    source, n_workers, num_iters, eval_every, unroll,
+                    sketch_dim, partition, mesh, sig,
+                )
+            _PROGRAM_CACHE[cache_key] = program
+    with TraceAnnotation("repro.sweep.call"):
+        times, losses, ks = program(params0, data, flat_cells, flat_keys)
 
-    n_evals = times.shape[1]
-    times, losses, ks = (
-        a.reshape(Gp, Rp, n_evals)[:G, :R] for a in (times, losses, ks)
-    )
-    iteration = np.minimum(
-        np.arange(1, n_evals + 1) * eval_every, num_iters
-    ).astype(np.int64)
-    return SweepResult(
-        time=times,
-        loss=losses,
-        k=ks,
-        iteration=iteration,
-        labels=tuple(c.name() for c in cases),
-    )
+    with TraceAnnotation("repro.sweep.unpad"):
+        n_evals = times.shape[1]
+        times, losses, ks = (
+            a.reshape(Gp, Rp, n_evals)[:G, :R] for a in (times, losses, ks)
+        )
+        iteration = np.minimum(
+            np.arange(1, n_evals + 1) * eval_every, num_iters
+        ).astype(np.int64)
+        return SweepResult(
+            time=times,
+            loss=losses,
+            k=ks,
+            iteration=iteration,
+            labels=tuple(c.name() for c in cases),
+        )
 
 
 def run_sweep(

@@ -7,6 +7,10 @@ train_step is ONE compiled program containing the paper's whole loop body:
   -> optimizer update -> renewal-clock advance -> Algorithm-1 controller
   update (k, Pflug counters, prev-gradient inner product).
 k is a traced int32 in the carried state, so adaptation never recompiles.
+Its parts carry execmode's named scopes (``repro.sampler``, ``repro.ranks``,
+``repro.grad`` for the model's forward and backward, ``repro.update`` for
+the optimizer, ``repro.controller``), and the post-update eval forward is
+``repro.eval``.
 
 The loop body is traced from the SAME per-mode step builders the sim engines
 use (``repro.core.execmode.make_mode_steps``): the straggler draw, renewal
@@ -223,7 +227,8 @@ def make_train_step(
         new_carry, k_used = steps[mode_idx](carry)
 
         # Post-update eval forward: the logged loss/ce are the new params'.
-        per_row, metrics = model.loss_fn(new_carry.params, batch)
+        with jax.named_scope("repro.eval"):
+            per_row, metrics = model.loss_fn(new_carry.params, batch)
         t_iter = new_carry.sim_time - state.sim_time
         out_metrics = {
             "loss": jnp.mean(per_row),

@@ -254,8 +254,10 @@ def _run_simulation(args):
 def main(argv=None):
     """Parse ``argv`` and train (or ``--simulate``).  An LM run returns
     ``{"compile_s": ..., "steps": [per-step metric dicts]}``: each step's
-    ``ce``, ``k``, simulated ``sim_time``/``iter_time`` and ``step_s``, the
-    step's wall time to ``block_until_ready``."""
+    ``ce``, ``k``, simulated ``sim_time``/``iter_time``, ``step_s``, the
+    step's wall time to ``block_until_ready``, and ``traces``/``compiles``,
+    what ``cache.compile_stats()`` rose by over the whole step, batch draw
+    included (the step that recompiled shows a non-zero count)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true",
@@ -433,6 +435,7 @@ def main(argv=None):
         step_fn = None
         t_start = time.perf_counter()
         for step in range(start, args.steps):
+            before = cache_lib.compile_stats()
             tokens, targets = data.batch_at(step)
             batch = {"tokens": tokens, "targets": targets}
             if cfg.family == "vlm":
@@ -449,13 +452,17 @@ def main(argv=None):
             t0 = time.perf_counter()
             state, metrics = step_fn(state, batch, sub)
             jax.block_until_ready((state, metrics))
+            step_s = time.perf_counter() - t0
+            after = cache_lib.compile_stats()
             record = {
                 "step": step,
                 "ce": float(metrics["ce"]),
                 "k": int(metrics["k"]),
                 "sim_time": float(metrics["sim_time"]),
                 "iter_time": float(metrics["iter_time"]),
-                "step_s": time.perf_counter() - t0,
+                "step_s": step_s,
+                "traces": after["traces"] - before["traces"],
+                "compiles": after["compiles"] - before["compiles"],
             }
             steps.append(record)
             if step % args.log_every == 0 or step == args.steps - 1:
@@ -465,6 +472,7 @@ def main(argv=None):
                     "k": record["k"],
                     "sim_time": round(record["sim_time"], 2),
                     "iter_time": round(record["iter_time"], 3),
+                    "compiles": record["compiles"],
                     "wall_s": round(time.perf_counter() - t_start, 1),
                 }), flush=True)
             if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
