@@ -1162,6 +1162,42 @@ def _build_grid_program(
     return jax.jit(run_grid, donate_argnums=dispatch_donation())
 
 
+def _lane_layout(stacked: _CellParams, G: int, R: int, mc: int, mr: int):
+    """The flat lane axis of a G x R grid on an (mc, mr) mesh, built on the host.
+
+    Each grid axis is padded to its mesh-axis multiple; the padded lanes are
+    sliced off before results are returned.  Cells pad with EMPTY all-zero
+    parameter rows (inert: zero-rate samplers draw +inf, n_active=0 holds
+    all data out — and any junk they compute stays confined to their own
+    lanes, there is no cross-lane arithmetic — never copies of a real cell,
+    so padding can't amplify real compute); replicas pad by repeating key 0.
+    The padded (Gp, Rp) grid then flattens CELL-MAJOR into the (Gp*Rp,)
+    lane axis the program vmaps over, so sharding that one axis over
+    ("cells", "replicas") hands each device a contiguous equal lane block.
+
+    Returns ``(flat_cells, key_index, Gp, Rp)``: ``stacked`` with every leaf
+    a NumPy array of Gp*Rp lanes (plain copies, no arithmetic), and the
+    index of the replica key each lane draws (``keys[key_index]``).
+    """
+    Gp, Rp = G + (-G) % mc, R + (-R) % mr
+    if Gp * Rp == mc * mr:
+        # One lane per device: pad the replicas once more so every device
+        # holds at least two (XLA drops size-1 batch dimensions, which
+        # would hand a one-lane program differently rounding kernels —
+        # run_monte_carlo pads a lone replica the same way).
+        Rp += mr
+    cell_idx = np.repeat(np.arange(Gp), Rp)
+    rep_idx = np.tile(np.arange(Rp), Gp)
+
+    def lanes(a):
+        a = np.asarray(a)
+        if Gp > G:
+            a = np.concatenate([a, np.zeros((Gp - G,) + a.shape[1:], a.dtype)])
+        return a[cell_idx]
+
+    return jax.tree.map(lanes, stacked), np.where(rep_idx < R, rep_idx, 0), Gp, Rp
+
+
 @functools.partial(jax.profiler.annotate_function, name="repro.sweep.run")
 def run_sweep_source(
     source: GradSource,
@@ -1258,8 +1294,10 @@ def run_sweep_source(
     The host work of a call shows in a profile as a ``repro.sweep.run``
     span around the call and one child span per phase:
     ``repro.sweep.cells`` (signature, per-cell leaves, stack),
-    ``repro.sweep.layout`` (padding, the flat lane index and its gathers),
-    ``repro.sweep.place`` (placement on the mesh), ``repro.sweep.program``
+    ``repro.sweep.layout`` (the padded flat lane leaves, built on the host
+    with NumPy, and the one gather of the replica keys),
+    ``repro.sweep.place`` (every lane leaf to the device once, on the mesh
+    if there is one), ``repro.sweep.program``
     (program-cache lookup, with ``repro.sweep.build`` on a miss),
     ``repro.sweep.call`` (the dispatch; a trace and compile land here) and
     ``repro.sweep.unpad`` (slicing the padding off the outputs).
@@ -1354,53 +1392,18 @@ def run_sweep_source(
             mc, mr = mesh.shape["cells"], mesh.shape["replicas"]
             n_proc = jax.process_count()
 
-        # Pad each grid axis to its mesh-axis multiple; padded lanes are sliced
-        # off before results are returned.  Cells pad with EMPTY all-zero
-        # parameter rows (inert: zero-rate samplers draw +inf, n_active=0 holds
-        # all data out — and any junk they compute stays confined to their own
-        # lanes, there is no cross-lane arithmetic — never gathered copies of a
-        # real cell, so padding can't amplify real compute); replicas pad by
-        # repeating key 0.  The padded (Gp, Rp) grid then flattens CELL-MAJOR
-        # into the (Gp*Rp,) lane axis the program vmaps over, so sharding that
-        # one axis over ("cells", "replicas") hands each device a contiguous
-        # equal lane block.
-        Gp, Rp = G + (-G) % mc, R + (-R) % mr
-        if Gp * Rp == mc * mr:
-            # One lane per device: pad the replicas once more so every device
-            # holds at least two (XLA drops size-1 batch dimensions, which
-            # would hand a one-lane program differently rounding kernels —
-            # run_monte_carlo pads a lone replica the same way).
-            Rp += mr
-        padded_cells = jax.tree.map(
-            lambda a: np.concatenate(
-                [np.asarray(a), np.zeros((Gp - G,) + a.shape[1:], a.dtype)]
-            )
-            if Gp > G
-            else np.asarray(a),
-            stacked,
-        )
-        padded_keys = (
-            keys[np.concatenate([np.arange(R), np.zeros(Rp - R, np.int64)])]
-            if Rp > R
-            else keys
-        )
-        cell_idx = np.repeat(np.arange(Gp), Rp)
-        rep_idx = np.tile(np.arange(Rp), Gp)
-        flat_cells = jax.tree.map(lambda a: jnp.asarray(a)[cell_idx], padded_cells)
-        flat_keys = padded_keys[rep_idx]
+        flat_cells, key_index, Gp, Rp = _lane_layout(stacked, G, R, mc, mr)
+        flat_keys = keys[key_index]
 
-    if mesh is not None:
-        from repro.launch.sharding import place_spanning
+    with TraceAnnotation("repro.sweep.place"):
+        if mesh is None:
+            flat_cells = jax.device_put(flat_cells)
+        else:
+            from repro.launch.sharding import place_spanning
 
-        with TraceAnnotation("repro.sweep.place"):
-            lane_sharding = NamedSharding(mesh, P(("cells", "replicas")))
-            replicated = NamedSharding(mesh, P())
-            flat_cells = jax.tree.map(
-                lambda a: place_spanning(a, lane_sharding), flat_cells
-            )
-            flat_keys = place_spanning(flat_keys, lane_sharding)
-            params0 = jax.tree.map(lambda a: place_spanning(a, replicated), params0)
-            data = jax.tree.map(lambda a: place_spanning(a, replicated), data)
+            lanes = NamedSharding(mesh, P(("cells", "replicas")))
+            flat_cells, flat_keys = place_spanning((flat_cells, flat_keys), lanes)
+            params0, data = place_spanning((params0, data), NamedSharding(mesh, P()))
 
     with TraceAnnotation("repro.sweep.program"):
         cache_key = (

@@ -231,20 +231,23 @@ def replicated(mesh: Mesh):
     return NamedSharding(mesh, P())
 
 
-def place_spanning(x, sharding: NamedSharding):
-    """Place one host-local array under ``sharding``, spanning processes.
+def place_spanning(tree, sharding: NamedSharding):
+    """Place a pytree of host-local arrays under ``sharding``, spanning processes.
 
-    Single-process this is ``jax.device_put`` (the historical path, bitwise
-    no-op on the values).  Multi-process, ``device_put`` cannot build an
-    array whose shards live on non-addressable devices — each process
-    instead materializes only its addressable shards via
-    ``jax.make_array_from_callback`` (every process must hold the full
-    host-side ``x``, which sweep dispatch guarantees: cell leaves and keys
+    Single-process this is one ``jax.device_put`` of the whole tree (a
+    bitwise no-op on the values).  Multi-process, ``device_put`` cannot
+    build an array whose shards live on non-addressable devices — each
+    process instead materializes only its addressable shards of each leaf
+    via ``jax.make_array_from_callback`` (every process must hold the full
+    host-side leaves, which sweep dispatch guarantees: cell leaves and keys
     are computed from the same host inputs on every process)."""
     if jax.process_count() == 1:
-        return jax.device_put(x, sharding)
-    shape = np.shape(x)
-    return jax.make_array_from_callback(shape, sharding, lambda idx: x[idx])
+        return jax.device_put(tree, sharding)
+
+    def place(x):
+        return jax.make_array_from_callback(np.shape(x), sharding, lambda idx: x[idx])
+
+    return jax.tree.map(place, tree)
 
 
 def activation_resolver(mesh: Mesh):
